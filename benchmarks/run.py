@@ -86,6 +86,9 @@ def main() -> None:
         print(f"unknown benchmark(s) {unknown}; available: {list(ALL)}",
               file=sys.stderr)
         sys.exit(2)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

@@ -29,7 +29,7 @@ from repro.core import (
 )
 from repro.configs import get_config
 from repro.data import SyntheticDataset
-from repro.launch.mesh import make_arnold_mesh, mesh_group_spread
+from repro.launch.mesh import make_arnold_mesh, make_mesh, mesh_group_spread
 from repro.models import ModelOptions, build_model
 from repro.optim import AdamWConfig, init_opt_state
 from repro.parallel import sharding as shd
@@ -67,7 +67,7 @@ def main():
     # -- 4./5. mesh from the placement ---------------------------------------
     mesh = make_arnold_mesh(res.placement, tp=job.tp, shape=(8, 8),
                             axes=("data", "model"))
-    naive = jax.make_mesh((8, 8), ("data", "model"))
+    naive = make_mesh((8, 8), ("data", "model"))
     for name, m in [("arnold", mesh), ("naive", naive)]:
         print(f"{name} mesh: model-axis spread="
               f"{mesh_group_spread(m, 'model', DEVICES_PER_POD)}, "

@@ -55,22 +55,28 @@ class ModelSpec:
 
 @dataclasses.dataclass(frozen=True)
 class JobSpec:
-    """User-facing job request: #GPUs + parallelism degrees + model."""
+    """User-facing job request: #GPUs + parallelism degrees + model.
+
+    ``gpus_per_node`` is the node size jobs are granular in: 8 GPUs for the
+    paper's clusters (and the simulator), the chips of one host on a TPU.
+    """
 
     n_gpus: int
     tp: int
     pp: int
     model: ModelSpec
     gpu_type: str = "H800"
+    gpus_per_node: int = GPUS_PER_NODE
 
     def __post_init__(self):
         if self.n_gpus % (self.tp * self.pp):
             raise ValueError(
                 f"n_gpus={self.n_gpus} not divisible by tp*pp={self.tp * self.pp}"
             )
-        if self.n_gpus % GPUS_PER_NODE:
-            raise ValueError("jobs are node-granular (8 GPUs per node)")
-        if GPUS_PER_NODE % self.tp:
+        if self.n_gpus % self.gpus_per_node:
+            raise ValueError(f"jobs are node-granular ({self.gpus_per_node} "
+                             "GPUs per node)")
+        if self.gpus_per_node % self.tp:
             raise ValueError("TP must divide the node size (TP stays intra-node, §2)")
 
     @property
@@ -79,7 +85,7 @@ class JobSpec:
 
     @property
     def n_nodes(self) -> int:
-        return self.n_gpus // GPUS_PER_NODE
+        return self.n_gpus // self.gpus_per_node
 
     @property
     def n_microbatches(self) -> int:
@@ -105,7 +111,7 @@ def dp_volume_bytes(job: JobSpec) -> float:
     emb = m.hidden * (m.vocab + m.seq_len)
     attn = 4 * m.hidden**2 + 2 * m.hidden
     if m.is_moe:
-        ep = min(m.n_experts, GPUS_PER_NODE // job.tp * job.n_nodes // job.pp)
+        ep = min(m.n_experts, job.gpus_per_node // job.tp * job.n_nodes // job.pp)
         ep = max(1, min(ep, job.dp * job.tp))  # experts sharded across the stage
         ffn = 3 * m.hidden * m.d_expert * m.n_experts / ep + 7 * m.hidden
     else:
@@ -184,10 +190,11 @@ class CommMatrix:
 
 def build_comm_matrix(job: JobSpec) -> CommMatrix:
     """Eq. 1 + Appendix C: derive the matrix shape and volume annotations."""
-    nodes_per_pp_group_stage = GPUS_PER_NODE // job.tp  # DP replicas per node
+    nodes_per_pp_group_stage = job.gpus_per_node // job.tp  # DP replicas per node
     if job.dp % nodes_per_pp_group_stage:
         raise ValueError(
-            f"DP={job.dp} must be divisible by 8/TP={nodes_per_pp_group_stage} "
+            f"DP={job.dp} must be divisible by {job.gpus_per_node}/TP="
+            f"{nodes_per_pp_group_stage} "
             "for node-granular rows (Eq. 1)"
         )
     n_rows = job.dp // nodes_per_pp_group_stage

@@ -49,5 +49,5 @@ def device_permutation(
     placement: Placement, tp: int, gpus_per_node: int = GPUS_PER_NODE
 ) -> list[int]:
     """Flat physical-GPU permutation in logical order (pp, dp, tp) -- feed to
-    ``jax.make_mesh(..., devices=devices[perm])``-style constructors."""
+    ``repro.launch.mesh.make_mesh(..., devices=devices[perm])``."""
     return [int(g) for g in logical_to_physical_gpus(placement, tp, gpus_per_node).ravel()]

@@ -42,7 +42,7 @@ from repro.core.comm_matrix import build_comm_matrix
 from repro.core.mip import Infeasible
 from repro.core.netmodel import fabric_net_model
 from repro.core.spread import Placement, max_spreads
-from repro.core.topology import GPUS_PER_NODE, Cluster
+from repro.core.topology import Cluster
 
 #: ladder tiers, least to most disruptive.
 TIERS = ("backup", "domain", "warm", "shrink", "restart")
@@ -263,7 +263,7 @@ class BoundRepair:
         new_a = np.delete(a, r, axis=0)
         job = self.placement.comm.job
         new_job = dataclasses.replace(
-            job, n_gpus=job.n_gpus - GPUS_PER_NODE * a.shape[1]
+            job, n_gpus=job.n_gpus - job.gpus_per_node * a.shape[1]
         )
         healthy = [n for n in row_nodes if n not in self.dead]
         self.cluster.release(healthy)
@@ -318,7 +318,7 @@ class BoundRepair:
         new_a = np.vstack([a, np.array(picked, dtype=a.dtype)])
         job = self.placement.comm.job
         new_job = dataclasses.replace(
-            job, n_gpus=job.n_gpus + GPUS_PER_NODE * n_cols
+            job, n_gpus=job.n_gpus + job.gpus_per_node * n_cols
         )
         self.placement = Placement(
             comm=build_comm_matrix(new_job), assignment=new_a,
@@ -621,7 +621,7 @@ class ElasticRepairPolicy:
                     assignment = np.delete(assignment, r, axis=0)
                     job = comm.job
                     comm = build_comm_matrix(dataclasses.replace(
-                        job, n_gpus=job.n_gpus - GPUS_PER_NODE * prev.comm.n_cols
+                        job, n_gpus=job.n_gpus - job.gpus_per_node * prev.comm.n_cols
                     ))
                     tiers.append("shrink")
                     continue

@@ -21,8 +21,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, loga_ref, y_ref, s_final_ref, s_scr,
-                *, n_chunks: int, chunk: int):
+def _ssd_kernel(x_ref, b_ref, c_ref, rows_ref, cols_ref, y_ref, s_final_ref,
+                s_scr, *, n_chunks: int, chunk: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -32,24 +32,32 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, loga_ref, y_ref, s_final_ref, s_scr
     x = x_ref[0, 0].astype(jnp.float32)          # (cs, P)
     B = b_ref[0, 0].astype(jnp.float32)          # (cs, N)
     C = c_ref[0, 0].astype(jnp.float32)          # (cs, N)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (cs,)
-    loga = loga_ref[0, 0].astype(jnp.float32)    # (cs,)
+    rows = rows_ref[0, 0]                        # (2, cs): dt, loga as rows
+    cols = cols_ref[0, 0]                        # (cs, 2): the same as columns
+    dt_row, loga_row = rows[0:1, :], rows[1:2, :]
+    dt_col, loga_col = cols[:, 0:1], cols[:, 1:2]
     S = s_scr[...]                               # (P, N) carried fp32 state
 
-    cum = jnp.cumsum(loga)
-    decay = cum[:, None] - cum[None, :]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    gate = jnp.where(tri, jnp.exp(decay), 0.0)
+    # inclusive cumsum of loga along the chunk, as a column (over t) and as
+    # a row (over u), by masked reductions (no in-kernel transpose)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    u_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = t_idx >= u_idx
+    cum_col = jnp.sum(jnp.where(tri, loga_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(t_idx <= u_idx, loga_col, 0.0), axis=0,
+                      keepdims=True)
+    total = jnp.sum(loga_row, axis=1, keepdims=True)      # (1, 1)
+
+    gate = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
     cb = jnp.dot(C, B.T, preferred_element_type=jnp.float32)
-    w = gate * cb * dt[None, :]
+    w = gate * cb * dt_row
     y_intra = jnp.dot(w, x, preferred_element_type=jnp.float32)
-    y_state = jnp.dot(C, S.T, preferred_element_type=jnp.float32) * jnp.exp(cum)[:, None]
+    y_state = jnp.dot(C, S.T, preferred_element_type=jnp.float32) * jnp.exp(cum_col)
     y_ref[0, 0] = (y_intra + y_state).astype(y_ref.dtype)
 
-    w_state = jnp.exp(cum[-1] - cum) * dt        # (cs,)
-    S_new = S * jnp.exp(cum[-1]) + jnp.dot(
-        (x * w_state[:, None]).T, B, preferred_element_type=jnp.float32
+    w_state = jnp.exp(total - cum_col) * dt_col  # (cs, 1)
+    S_new = S * jnp.exp(total) + jnp.dot(
+        (x * w_state).T, B, preferred_element_type=jnp.float32
     )
     s_scr[...] = S_new
 
@@ -66,6 +74,11 @@ def ssd_chunk_scan(x, B, C, dt, loga, chunk: int = 128, interpret: bool = False)
     chunk = min(chunk, s)
     assert s % chunk == 0, (s, chunk)
     n_chunks = s // chunk
+    # dt/loga go in twice, as rows (b, H, 2, s) and as columns (b, H, s, 2):
+    # a (1, 1, chunk) block of a rank-3 array breaks the TPU's (8, 128)
+    # tiling rule, while these two blocks satisfy it.
+    gates = jnp.stack([dt, loga], axis=-1).astype(jnp.float32)
+    rows = jnp.swapaxes(gates, -1, -2)
 
     kernel = functools.partial(_ssd_kernel, n_chunks=n_chunks, chunk=chunk)
     grid = (b, H, n_chunks)
@@ -76,8 +89,8 @@ def ssd_chunk_scan(x, B, C, dt, loga, chunk: int = 128, interpret: bool = False)
             pl.BlockSpec((1, 1, chunk, P), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda ib, ih, ic: (ib, ih, ic, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda ib, ih, ic: (ib, ih, ic)),
-            pl.BlockSpec((1, 1, chunk), lambda ib, ih, ic: (ib, ih, ic)),
+            pl.BlockSpec((1, 1, 2, chunk), lambda ib, ih, ic: (ib, ih, 0, ic)),
+            pl.BlockSpec((1, 1, chunk, 2), lambda ib, ih, ic: (ib, ih, ic, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda ib, ih, ic: (ib, ih, ic, 0)),
@@ -92,5 +105,5 @@ def ssd_chunk_scan(x, B, C, dt, loga, chunk: int = 128, interpret: bool = False)
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, B, C, dt, loga)
+    )(x, B, C, rows, gates)
     return y, s_final

@@ -1,5 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 placeholder CPU devices: pinned to the CPU, with the device count
+# appended to any XLA flags the caller set
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512"]))
 
 # --- everything below may import jax -----------------------------------------
 """Multi-pod dry-run (deliverable e): lower + compile every

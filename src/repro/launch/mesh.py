@@ -1,5 +1,10 @@
 """Production mesh construction + Arnold-aligned device ordering.
 
+``make_mesh`` is the one mesh constructor every caller uses: its axes are
+``Auto`` (the sharding rules in ``parallel.sharding`` place arrays through
+``with_sharding_constraint``, which only accepts Auto axes; ``jax.make_mesh``
+defaults to Explicit ones).
+
 ``make_production_mesh`` is a FUNCTION (importing this module never touches
 jax device state): single-pod = (16, 16) over (data, model) = 256 chips;
 multi-pod = (2, 16, 16) over (pod, data, model) = 512 chips.
@@ -17,16 +22,26 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.core.rank_assign import device_permutation
 from repro.core.spread import Placement
 
 
+def make_mesh(shape: tuple, axes: tuple, devices=None) -> Mesh:
+    """Mesh of ``shape`` over ``axes`` with Auto axis types.  ``devices``
+    (in mesh order) defaults to JAX's own device order for the shape."""
+    auto = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=auto)
+    return Mesh(np.array(devices, dtype=object).reshape(shape), axes,
+                axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_arnold_mesh(
@@ -47,8 +62,7 @@ def make_arnold_mesh(
     perm = device_permutation(placement, tp, gpus_per_node)
     if len(perm) > len(devices):
         raise ValueError(f"placement needs {len(perm)} devices, have {len(devices)}")
-    dev_arr = np.array([devices[i] for i in perm], dtype=object).reshape(shape)
-    return Mesh(dev_arr, axes)
+    return make_mesh(shape, axes, devices=[devices[i] for i in perm])
 
 
 def mesh_device_minipods(mesh: Mesh, devices_per_pod: int) -> np.ndarray:

@@ -1,10 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimbing driver: run named experiment variants of one
 (arch x shape) cell on the single-pod mesh, re-deriving the roofline per
 variant, and append hypothesis->before->after records to
 reports/perf_<arch>_<shape>.json.
+
+Like the dry run it imports first, it runs on 512 placeholder CPU devices.
 
 Usage:
     python -m repro.launch.perf --arch granite-8b --shape train_4k \

@@ -2,9 +2,11 @@
 
 Wires the whole stack together: config -> model -> synthetic data pipeline ->
 AdamW (+schedule) -> fault-tolerant Trainer with checkpoint-restart, and
-optionally an Arnold-scheduled mesh (``--devices N --mesh-shape dxm`` builds
-an N-fake-device cluster, runs the MILP placement, permutes the mesh, and
-trains under pjit).
+optionally a sharded run (``--devices N --mesh-shape dxm``: a mesh over the
+chips, or over N virtual devices on the CPU; with ``--arnold`` the MILP
+placement permutes the mesh), trained under pjit with state created and
+restored already sharded.  ``--full`` (the published widths) computes in
+bfloat16.
 """
 
 import argparse
@@ -12,7 +14,8 @@ import os
 import sys
 
 
-def main(argv=None):
+def run(argv=None) -> list[float]:
+    """Train as the command line ``argv`` says; return the logged losses."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm-2b")
     ap.add_argument("--full", action="store_true",
@@ -26,7 +29,8 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--devices", type=int, default=0,
-                    help="fake host devices for a sharded run (0 = single)")
+                    help="devices of a sharded run (0 = one device); on the "
+                         "CPU, that many virtual host devices")
     ap.add_argument("--mesh-shape", default="2x4",
                     help="dataxmodel for the sharded run")
     ap.add_argument("--arnold", action="store_true",
@@ -39,23 +43,25 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
-        )
+        # virtual host devices for a sharded run on the CPU (no effect on a
+        # chip, and none once JAX has started in this process)
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+            os.environ.get("XLA_FLAGS"),
+            f"--xla_force_host_platform_device_count={args.devices}"]))
 
-    import jax
     from repro.configs import get_config
     from repro.data import SyntheticDataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import ModelOptions, build_model
-    from repro.models.whisper import N_FRAMES
     from repro.optim import AdamWConfig, get_schedule
     from repro.train import Trainer, TrainerConfig
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
     opts = ModelOptions(
-        compute_dtype="float32" if not args.devices else "bfloat16",
+        compute_dtype="bfloat16" if args.full or args.devices else "float32",
         remat=bool(args.full),
     )
     model = build_model(cfg, opts)
@@ -83,51 +89,58 @@ def main(argv=None):
             f"gnorm {h['grad_norm']:.3f}  {h['step_time']*1e3:.0f} ms",
             flush=True,
         ),
+        mesh=build_mesh(args, cfg) if args.devices else None,
     )
-
-    if args.devices:
-        # sharded run: optionally Arnold-ordered mesh
-        from repro.core import (
-            CharacterizationDB, Cluster, JobSpec, ModelSpec, ScheduleRequest,
-            build_comm_matrix, get_scheduler,
-        )
-        from repro.launch.mesh import make_arnold_mesh, mesh_group_spread
-        from repro.parallel import sharding as shd
-        from repro.train import make_train_step
-
-        d, m = (int(x) for x in args.mesh_shape.split("x"))
-        assert d * m <= args.devices
-        if args.arnold:
-            nodes = args.devices // 8
-            cluster = Cluster.uniform(max(2, nodes // 4), 4)
-            mspec = ModelSpec(name=cfg.name, hidden=cfg.d_model,
-                              layers=cfg.n_layers, vocab=cfg.vocab,
-                              seq_len=args.seq_len, global_batch=args.global_batch,
-                              d_ff=cfg.d_ff or 4 * cfg.d_model)
-            job = JobSpec(n_gpus=d * m, tp=min(m, 8), pp=1, model=mspec)
-            comm = build_comm_matrix(job)
-            alpha, beta, unit = CharacterizationDB().affinity_for(comm)
-            res = get_scheduler(args.scheduler).schedule(ScheduleRequest(
-                comm=comm, cluster=cluster, alpha=alpha, beta=beta, unit=unit,
-            ))
-            mesh = make_arnold_mesh(res.placement, tp=job.tp, shape=(d, m),
-                                    axes=("data", "model"))
-            print(f"Arnold placement [{res.method}]: pods={res.n_pods_used()} "
-                  f"spread(data axis)={mesh_group_spread(mesh, 'data', 32)}")
-        else:
-            mesh = jax.make_mesh((d, m), ("data", "model"))
-        with shd.activate(mesh):
-            trainer.step_fn = make_train_step(
-                model, opt, mesh=mesh, microbatches=args.microbatches
-            )(jax.eval_shape(lambda: {
-                k: jax.numpy.asarray(v) for k, v in ds.batch(0).items()
-            }))
-            history = trainer.run()
-    else:
-        history = trainer.run()
-
+    trainer.run()
     losses = trainer.losses()
     print(f"done: first logged loss {losses[0]:.4f} -> last {losses[-1]:.4f}")
+    return losses
+
+
+def build_mesh(args, cfg):
+    """The (data, model) mesh of a sharded run: JAX's device order, or with
+    ``--arnold`` the order of the scheduler's placement."""
+    import jax
+    from repro.launch.mesh import make_arnold_mesh, make_mesh, mesh_group_spread
+
+    d, m = (int(x) for x in args.mesh_shape.split("x"))
+    if d * m > min(args.devices, len(jax.devices())):
+        raise SystemExit(f"mesh {d}x{m} needs {d * m} devices; "
+                         f"--devices {args.devices}, {len(jax.devices())} present")
+    if not args.arnold:
+        return make_mesh((d, m), ("data", "model"))
+
+    from repro.core import (
+        CharacterizationDB, Cluster, JobSpec, ModelSpec, ScheduleRequest,
+        build_comm_matrix, get_scheduler,
+    )
+
+    # A node is the chips of one host (4 on a TPU v5e host, every virtual
+    # device on the CPU), or the whole job where that is smaller; the
+    # cluster is the devices present, in pods of up to 4 nodes.
+    per_node = min(jax.local_device_count(), d * m)
+    n_nodes = len(jax.devices()) // per_node
+    cluster = Cluster([min(4, n_nodes - i) for i in range(0, n_nodes, 4)])
+    mspec = ModelSpec(name=cfg.name, hidden=cfg.d_model,
+                      layers=cfg.n_layers, vocab=cfg.vocab,
+                      seq_len=args.seq_len, global_batch=args.global_batch,
+                      d_ff=cfg.d_ff or 4 * cfg.d_model)
+    job = JobSpec(n_gpus=d * m, tp=min(m, per_node), pp=1, model=mspec,
+                  gpus_per_node=per_node)
+    comm = build_comm_matrix(job)
+    alpha, beta, unit = CharacterizationDB().affinity_for(comm)
+    res = get_scheduler(args.scheduler).schedule(ScheduleRequest(
+        comm=comm, cluster=cluster, alpha=alpha, beta=beta, unit=unit,
+    ))
+    mesh = make_arnold_mesh(res.placement, tp=job.tp, shape=(d, m),
+                            axes=("data", "model"), gpus_per_node=per_node)
+    print(f"Arnold placement [{res.method}]: pods={res.n_pods_used()} "
+          f"spread(data axis)={mesh_group_spread(mesh, 'data', 4 * per_node)}")
+    return mesh
+
+
+def main(argv=None) -> int:
+    losses = run(argv)
     return 0 if losses[-1] < losses[0] else 1
 
 

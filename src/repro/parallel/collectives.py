@@ -97,8 +97,7 @@ def make_dp_grad_fn(loss_fn, mesh: Mesh, axis_name: str = "data",
     """shard_map data-parallel value-and-grad with compressed gradient
     all-reduce: each shard computes grads on its micro-shard of the batch,
     then ``compressed_psum_mean`` synchronizes them."""
-    from jax.experimental.shard_map import shard_map
-
+    
     def local(params, batch):
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
         grads = compressed_psum_mean(grads, axis_name, scheme)
@@ -106,10 +105,10 @@ def make_dp_grad_fn(loss_fn, mesh: Mesh, axis_name: str = "data",
         return loss, grads
 
     batch_spec = P(axis_name)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), jax.tree.map(lambda _: batch_spec, {"tokens": 0, "labels": 0})),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )

@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -97,12 +96,12 @@ def make_pp_loss_fn(
             y = pipe(x_stage, x0)
             return head_loss_fn(shared_params, y, batch)
 
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(axis_name), P(), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(params["stages"], params["shared"], batch)
 
     return loss

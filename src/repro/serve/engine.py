@@ -105,6 +105,26 @@ class _Lane:
     committed_blocks: int
 
 
+def engine_steps(model):
+    """The engine's two device programs for ``model``, jitted with the page
+    pool donated: ``(decode, prefill)``, each returning greedy tokens and
+    the new pages."""
+
+    def decode_fn(params, pages, tables, lengths, tokens, active):
+        logits, pages = model.decode_step_paged(
+            params, pages, tables, lengths, tokens, active
+        )
+        return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), pages
+
+    def prefill_fn(params, pages, table, length, tokens):
+        logits, pages = model.prefill_paged(params, pages, table, length, tokens)
+        last = jnp.take(logits[0], length - 1, axis=0)
+        return jnp.argmax(last).astype(jnp.int32), pages
+
+    return (jax.jit(decode_fn, donate_argnums=(1,)),
+            jax.jit(prefill_fn, donate_argnums=(1,)))
+
+
 class ServeEngine:
     """Continuous-batching engine over one ``DecoderLM`` replica."""
 
@@ -120,20 +140,7 @@ class ServeEngine:
         self._t0: Optional[float] = None
         self.stats = EngineStats()
         self.results: list[GenerationResult] = []
-
-        def decode_fn(params, pages, tables, lengths, tokens, active):
-            logits, pages = model.decode_step_paged(
-                params, pages, tables, lengths, tokens, active
-            )
-            return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), pages
-
-        def prefill_fn(params, pages, table, length, tokens):
-            logits, pages = model.prefill_paged(params, pages, table, length, tokens)
-            last = jnp.take(logits[0], length - 1, axis=0)
-            return jnp.argmax(last).astype(jnp.int32), pages
-
-        self._decode = jax.jit(decode_fn, donate_argnums=(1,))
-        self._prefill = jax.jit(prefill_fn, donate_argnums=(1,))
+        self._decode, self._prefill = engine_steps(model)
 
     # ------------------------------------------------------------------ clock
     def now(self) -> float:

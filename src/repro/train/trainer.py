@@ -9,6 +9,7 @@ mid-run to prove it).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Optional
@@ -19,6 +20,7 @@ import numpy as np
 from repro.checkpoint import Checkpointer
 from repro.data import Prefetcher, SyntheticDataset
 from repro.optim import AdamWConfig, init_opt_state
+from repro.parallel import sharding as shd
 from repro.train.train_step import make_train_step
 
 
@@ -55,6 +57,10 @@ class FaultInjector:
 
 
 class Trainer:
+    """With a ``mesh``, the step runs under the sharding rules of
+    ``parallel.sharding``, and state is created and restored already
+    sharded, so no device ever holds the whole of it."""
+
     def __init__(
         self,
         model,
@@ -64,6 +70,7 @@ class Trainer:
         cfg: TrainerConfig = TrainerConfig(),
         fault_injector: Optional[FaultInjector] = None,
         on_step: Optional[Callable] = None,
+        mesh=None,
     ):
         self.model = model
         self.dataset = dataset
@@ -73,21 +80,42 @@ class Trainer:
                                  use_async=cfg.async_ckpt)
         self.fault = fault_injector
         self.on_step = on_step
-        self.step_fn = make_train_step(model, opt_cfg, microbatches=cfg.microbatches)
+        self.mesh = mesh
         self.history: list[dict] = []
+        self.state_shardings = None  # (params, opt) shardings under a mesh
+        if mesh is None:
+            self.step_fn = make_train_step(model, opt_cfg,
+                                           microbatches=cfg.microbatches)
+            return
+        build = make_train_step(model, opt_cfg, mesh=mesh,
+                                microbatches=cfg.microbatches)
+        batch_shape = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), dataset.batch(0))
+        self.step_fn = build(batch_shape)
+        self.state_shardings = (build.param_shardings, build.opt_shardings)
 
     # ------------------------------------------------------------------ state
-    def _init_state(self):
+    def _new_state(self):
         params = self.model.init(jax.random.PRNGKey(self.cfg.seed))
         return params, init_opt_state(params)
 
+    def _init_state(self):
+        if self.state_shardings is None:
+            return self._new_state()
+        return jax.jit(self._new_state, out_shardings=self.state_shardings)()
+
     def _restore_or_init(self):
         latest = self.ckpt.latest_step()
-        params_t, opt_t = jax.eval_shape(self._init_state)
         if latest is None:
             params, opt_state = self._init_state()
             return params, opt_state, 0
-        state = self.ckpt.restore({"params": params_t, "opt": opt_t}, step=latest)
+        params_t, opt_t = jax.eval_shape(self._new_state)
+        shardings = None
+        if self.state_shardings is not None:
+            p_shard, o_shard = self.state_shardings
+            shardings = {"params": p_shard, "opt": o_shard}
+        state = self.ckpt.restore({"params": params_t, "opt": opt_t},
+                                  step=latest, shardings=shardings)
         return state["params"], state["opt"], latest
 
     # -------------------------------------------------------------------- run
@@ -117,6 +145,12 @@ class Trainer:
                 self.history.append({"event": "restart", "error": str(e)})
 
     def _run_once(self):
+        mesh_ctx = (shd.activate(self.mesh) if self.mesh is not None
+                    else contextlib.nullcontext())
+        with mesh_ctx:
+            self._run_steps()
+
+    def _run_steps(self):
         params, opt_state, start = self._restore_or_init()
         prefetch = Prefetcher(self.dataset, start_step=start)
         try:

@@ -2,10 +2,27 @@
 NOT set here -- smoke tests and benches must see the single real CPU device.
 Multi-device tests spawn subprocesses with their own XLA_FLAGS."""
 
+import os
+import pathlib
+
 import numpy as np
 import pytest
 
 from repro.core import Cluster, JobSpec, ModelSpec, build_comm_matrix
+
+#: root of the checkout these tests belong to (children run from here)
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+def cpu_child_env(**extra) -> dict:
+    """Environment for a child python process: this one's, held to the CPU,
+    with this checkout's ``src`` first on ``PYTHONPATH``."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    path = [str(REPO_ROOT / "src")] + [p for p in [env.get("PYTHONPATH")] if p]
+    env["PYTHONPATH"] = os.pathsep.join(path)
+    env.update(extra)
+    return env
 
 
 @pytest.fixture

@@ -9,6 +9,7 @@ import textwrap
 import numpy as np
 import pytest
 
+from conftest import REPO_ROOT, cpu_child_env
 from repro.core import (
     Cluster,
     JobSpec,
@@ -71,7 +72,8 @@ class TestArnoldMeshOnDevices:
             import jax
             from repro.core import (Cluster, JobSpec, ModelSpec, ScheduleRequest,
                                     build_comm_matrix, get_scheduler)
-            from repro.launch.mesh import make_arnold_mesh, mesh_group_spread
+            from repro.launch.mesh import (make_arnold_mesh, make_mesh,
+                                           mesh_group_spread)
 
             cluster = Cluster.uniform(4, 2)  # 4 pods x 2 nodes (16 devs/pod)
             model = ModelSpec(name="m", hidden=1024, layers=8, vocab=5000,
@@ -81,7 +83,7 @@ class TestArnoldMeshOnDevices:
                 ScheduleRequest(comm=comm, cluster=cluster, alpha=0.0))
             mesh = make_arnold_mesh(res.placement, tp=8, shape=(8, 8),
                                     axes=("data", "model"))
-            naive = jax.make_mesh((8, 8), ("data", "model"))
+            naive = make_mesh((8, 8), ("data", "model"))
             out = {
                 "arnold_model": mesh_group_spread(mesh, "model", 16),
                 "naive_model": mesh_group_spread(naive, "model", 16),
@@ -92,8 +94,7 @@ class TestArnoldMeshOnDevices:
         """)
         proc = subprocess.run(
             [sys.executable, "-c", code], capture_output=True, text=True,
-            timeout=600, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-            cwd="/root/repo",
+            timeout=600, env=cpu_child_env(), cwd=REPO_ROOT,
         )
         assert proc.returncode == 0, proc.stderr
         out = json.loads(proc.stdout.strip().splitlines()[-1])

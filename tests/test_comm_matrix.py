@@ -51,6 +51,22 @@ class TestEq1:
         with pytest.raises(ValueError):
             JobSpec(n_gpus=100, tp=4, pp=2, model=model7b)
 
+    @pytest.mark.parametrize("per_node,n_gpus,tp,shape", [
+        (4, 4, 2, (1, 1)),     # one TPU v5e host: 4 chips, (data=2, model=2)
+        (4, 16, 2, (4, 1)),    # four such hosts
+        (8, 8, 4, (1, 1)),     # the paper's 8-GPU node
+    ])
+    def test_node_size_follows_the_host(self, model7b, per_node, n_gpus, tp, shape):
+        job = JobSpec(n_gpus=n_gpus, tp=tp, pp=1, model=model7b,
+                      gpus_per_node=per_node)
+        comm = build_comm_matrix(job)
+        assert comm.shape == shape
+        assert comm.n_cells == job.n_nodes == n_gpus // per_node
+
+    def test_default_node_rejects_a_four_chip_job(self, model7b):
+        with pytest.raises(ValueError, match="8 GPUs per node"):
+            JobSpec(n_gpus=4, tp=2, pp=1, model=model7b)
+
 
 class TestAppendixC:
     def test_paper_sanity_numbers(self, model7b):

@@ -9,6 +9,8 @@ import textwrap
 
 import pytest
 
+from conftest import REPO_ROOT, cpu_child_env
+
 BOOT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -16,6 +18,7 @@ import jax, json
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -24,8 +27,7 @@ def run_py(body: str) -> dict:
     proc = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        env=cpu_child_env(), cwd=REPO_ROOT,
     )
     assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
     return json.loads(proc.stdout.strip().splitlines()[-1])
@@ -37,7 +39,7 @@ class TestShardingRules:
             from repro.configs import get_config
             from repro.models import build_model, ModelOptions
             from repro.parallel.sharding import param_shardings, opt_shardings
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             cfg = get_config("glm4-9b").reduced()
             model = build_model(cfg, ModelOptions())
             pshape = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
@@ -61,7 +63,7 @@ class TestShardingRules:
     def test_kv_indivisible_degrades_to_replication(self):
         out = run_py("""
             from repro.parallel.sharding import resolve_spec, default_rules
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rules = default_rules(mesh.axis_names)
             s1 = resolve_spec(("batch", None, "kv_heads", None), (8, 128, 2, 64), mesh, rules)
             s2 = resolve_spec(("batch", None, "kv_heads", None), (8, 128, 4, 64), mesh, rules)
@@ -95,7 +97,7 @@ class TestShardingRules:
                 params, state, m1 = step1(params, state, batch)
 
             # sharded
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             params2 = model.init(jax.random.PRNGKey(0))
             state2 = init_opt_state(params2)
             with shd.activate(mesh):
@@ -109,10 +111,57 @@ class TestShardingRules:
         """)
         assert abs(out["l1"] - out["l2"]) < 1e-4, out
 
+    def test_trainer_state_sharded_at_init_and_restore(self):
+        """Under a mesh the Trainer creates and restores state with the
+        step's shardings, and a resumed run matches an uninterrupted one."""
+        out = run_py("""
+            import tempfile
+            from repro.configs import get_config
+            from repro.data import SyntheticDataset
+            from repro.models import build_model, ModelOptions
+            from repro.optim import AdamWConfig
+            from repro.train import Trainer, TrainerConfig
+
+            cfg = get_config("minicpm-2b").reduced()
+            model = build_model(cfg, ModelOptions(compute_dtype="float32", remat=False))
+            ds = SyntheticDataset(cfg.vocab, 16, 8)
+            mesh = make_mesh((2, 4), ("data", "model"))
+
+            def trainer(steps, ckpt_dir):
+                return Trainer(model, ds, AdamWConfig(lr=1e-3), ckpt_dir=ckpt_dir,
+                               cfg=TrainerConfig(total_steps=steps, ckpt_every=2,
+                                                 log_every=1), mesh=mesh)
+
+            def placed(state, shardings):
+                return all(a.sharding.is_equivalent_to(s, a.ndim)
+                           for a, s in zip(jax.tree.leaves(state),
+                                           jax.tree.leaves(shardings)))
+
+            resumed_dir = tempfile.mkdtemp()
+            first = trainer(2, resumed_dir)
+            fresh = first._init_state()
+            first.run()
+            second = trainer(4, resumed_dir)
+            params, opt, start = second._restore_or_init()
+            second.run()
+            whole = trainer(4, tempfile.mkdtemp())
+            whole.run()
+            split = [len(a.sharding.device_set) > 1 for a in jax.tree.leaves(fresh)]
+            print(json.dumps({
+                "fresh": placed(fresh, first.state_shardings),
+                "restored": placed((params, opt), second.state_shardings),
+                "start": start, "any_split": any(split),
+                "resumed": second.losses()[-1], "whole": whole.losses()[-1],
+            }))
+        """)
+        assert out["fresh"] and out["restored"] and out["any_split"], out
+        assert out["start"] == 2
+        assert abs(out["resumed"] - out["whole"]) < 1e-5, out
+
     def test_zero1_opt_state_sharded_over_data(self):
         out = run_py("""
             from repro.parallel.sharding import opt_spec
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             s = opt_spec("layers/attn/wq", (4, 512, 512), mesh)
             print(json.dumps({"spec": str(s)}))
         """)
@@ -125,11 +174,10 @@ class TestPipelineParallel:
         grad; boundary traffic equals Eq. 13."""
         out = run_py("""
             from repro.parallel.pipeline import pipeline_forward, pp_boundary_bytes
-            from jax.experimental.shard_map import shard_map
             from functools import partial
 
             S, m, mb, d = 4, 8, 2, 16
-            mesh = jax.make_mesh((S,), ("stage",))
+            mesh = make_mesh((S,), ("stage",))
             key = jax.random.PRNGKey(0)
             Ws = jax.random.normal(key, (S, d, d)) * 0.3
 
@@ -141,10 +189,10 @@ class TestPipelineParallel:
             def run_pp(Ws, x):
                 def inner(Wl, x):
                     return pipe(Wl[0], x)
-                return shard_map(inner, mesh=mesh,
+                return jax.shard_map(inner, mesh=mesh,
                                  in_specs=(jax.sharding.PartitionSpec("stage"), jax.sharding.PartitionSpec()),
                                  out_specs=jax.sharding.PartitionSpec(),
-                                 check_rep=False)(Ws, x)
+                                 check_vma=False)(Ws, x)
 
             x = jax.random.normal(jax.random.PRNGKey(1), (m, mb, d))
             y_pp = run_pp(Ws, x)
@@ -203,7 +251,7 @@ class TestFlashDecoding:
                 outs_ref.append(np.asarray(lg))
 
             # sharded: mesh (2,4); kv=3 % 4 != 0 -> seq-flash path
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             with shd.activate(mesh):
                 p_sh = shd.param_shardings(jax.eval_shape(
                     lambda: model.init(jax.random.PRNGKey(0))), mesh)
@@ -230,17 +278,16 @@ class TestCompressedCollectives:
     def test_compressed_psum_close_to_exact(self):
         out = run_py("""
             from repro.parallel.collectives import compressed_psum_mean
-            from jax.experimental.shard_map import shard_map
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
             def f(scheme):
                 def inner(x):
                     return compressed_psum_mean(x[0], "data", scheme)
-                return shard_map(inner, mesh=mesh,
+                return jax.shard_map(inner, mesh=mesh,
                                  in_specs=jax.sharding.PartitionSpec("data"),
                                  out_specs=jax.sharding.PartitionSpec(),
-                                 check_rep=False)(x)
+                                 check_vma=False)(x)
             exact = jnp.mean(x, 0)
             e16 = float(jnp.max(jnp.abs(f("fp16") - exact)))
             e8 = float(jnp.max(jnp.abs(f("int8") - exact)))

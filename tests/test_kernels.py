@@ -176,3 +176,15 @@ class TestOpsDispatch:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref.flash_attention_ref(q, k, v)), rtol=1e-6
         )
+
+    def test_pallas_needs_a_tpu(self):
+        x = jnp.ones((8, 128), jnp.float32)
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            ops.rmsnorm(x, jnp.ones((128,)), impl="pallas")
+
+    def test_interpret_only_when_asked(self):
+        x = rand(jax.random.PRNGKey(0), (8, 128), jnp.float32)
+        out = ops.rmsnorm(x, jnp.ones((128,)), impl="interpret")
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref.rmsnorm_ref(x, jnp.ones((128,)))),
+            rtol=1e-5, atol=1e-5)

@@ -8,6 +8,7 @@ import sys
 
 import pytest
 
+from conftest import REPO_ROOT, cpu_child_env
 from repro.configs import SHAPES, get_config
 from repro.launch.roofline import (
     Roofline,
@@ -90,8 +91,7 @@ class TestDryrunCell:
              "--arch", "whisper-tiny", "--shape", "decode_32k",
              "--mesh", "single", "--out", str(tmp_path)],
             capture_output=True, text=True, timeout=900,
-            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-            cwd="/root/repo",
+            env=cpu_child_env(), cwd=REPO_ROOT,
         )
         assert proc.returncode == 0, proc.stderr[-2000:]
         rec = json.loads((tmp_path / "dryrun.jsonl").read_text().splitlines()[0])
