@@ -32,6 +32,16 @@ Token accounting: the engine clock starts *after* jit warm-up
 (:meth:`ServeEngine.run` warms the decode step and every prefill bucket it
 will need), and every counted token is timestamped inside the measured
 window -- fixing the warm-up-token bug of the old fixed-batch demo.
+
+Host time inside the window is split by leaf spans (:mod:`repro.serve.spans`)
+that do not overlap: ``engine.evict``; per admission ``engine.admit.prefill``
+(page commitment, padding, prefill dispatch) and ``engine.admit.wait`` (the
+first token); per tick ``engine.tick.prepare`` (page growth, inputs, the
+block-table upload), ``engine.tick.dispatch`` (the decode call with its
+host-to-device copies and any compile), ``engine.tick.wait`` (the output)
+and ``engine.tick.commit`` (stats, lanes, finishing).  ``EngineStats.host``
+holds their calls and seconds, beside the compiles and the ``engine.gc``
+pauses counted in the same window.
 """
 
 from __future__ import annotations
@@ -47,6 +57,7 @@ import numpy as np
 
 from repro.serve.kv_cache import PagedCacheConfig, PagedKVCache
 from repro.serve.request import GenerationRequest, GenerationResult
+from repro.serve.spans import Spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +95,11 @@ class EngineStats:
     elapsed_s: float = 0.0
     occupancy: list[int] = dataclasses.field(default_factory=list)
     peak_pages_in_use: int = 0
+    # host spans (name -> [calls, seconds]), XLA compiles and their seconds,
+    # from the clock's start to the end of run()
+    host: dict[str, list] = dataclasses.field(default_factory=dict)
+    compiles: int = 0
+    compile_s: float = 0.0
 
     @property
     def tokens_per_s(self) -> float:
@@ -140,6 +156,7 @@ class ServeEngine:
         self._t0: Optional[float] = None
         self.stats = EngineStats()
         self.results: list[GenerationResult] = []
+        self._spans = Spans()
         self._decode, self._prefill = engine_steps(model)
 
     # ------------------------------------------------------------------ clock
@@ -178,19 +195,21 @@ class ServeEngine:
         """Grant a lane + page commitment, then prefill the prompt."""
         cfg = self.config
         prompt = list(request.prompt)
-        admitted = self.now()
-        need = self.cache.config.blocks_for(request.worst_case_tokens)
-        self._committed_blocks += need
-        self.cache.ensure_capacity(lane_id, len(prompt))
+        with self._spans.span("engine.admit.prefill"):
+            admitted = self.now()
+            need = self.cache.config.blocks_for(request.worst_case_tokens)
+            self._committed_blocks += need
+            self.cache.ensure_capacity(lane_id, len(prompt))
 
-        bucket = cfg.prefill_bucket(len(prompt))
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, : len(prompt)] = prompt
-        first, self.cache.pages = self._prefill(
-            self.params, self.cache.pages, self.cache.lane_table(lane_id),
-            jnp.int32(len(prompt)), jnp.asarray(padded),
-        )
-        first = int(jax.block_until_ready(first))
+            bucket = cfg.prefill_bucket(len(prompt))
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, : len(prompt)] = prompt
+            first, self.cache.pages = self._prefill(
+                self.params, self.cache.pages, self.cache.lane_table(lane_id),
+                jnp.int32(len(prompt)), jnp.asarray(padded),
+            )
+        with self._spans.span("engine.admit.wait"):
+            first = int(jax.block_until_ready(first))
         t = self.now()
         self.stats.prefills += 1
         self.stats.tokens_generated += 1
@@ -268,38 +287,44 @@ class ServeEngine:
         active_ids = [i for i, l in enumerate(self._lanes) if l is not None]
         if not active_ids:
             return
-        nb = self.config.max_batch
-        tokens = np.zeros((nb, 1), np.int32)
-        lengths = np.zeros(nb, np.int32)
-        active = np.zeros(nb, bool)
-        for i in active_ids:
-            lane = self._lanes[i]
-            # the incoming token is written at position `length`
-            self.cache.ensure_capacity(i, lane.length + 1)
-            tokens[i, 0] = lane.last_token
-            lengths[i] = lane.length
-            active[i] = True
-        out, self.cache.pages = self._decode(
-            self.params, self.cache.pages, self.cache.device_block_tables(),
-            jnp.asarray(lengths), jnp.asarray(tokens), jnp.asarray(active),
-        )
-        out = np.asarray(jax.block_until_ready(out))
-        t = self.now()
-        self.stats.decode_steps += 1
-        self.stats.occupancy.append(len(active_ids))
-        self.stats.peak_pages_in_use = max(
-            self.stats.peak_pages_in_use, self.cache.allocator.n_allocated
-        )
-        for i in active_ids:
-            lane = self._lanes[i]
-            token = int(out[i])
-            lane.length += 1
-            lane.last_token = token
-            lane.tokens.append(token)
-            lane.token_times.append(t)
-            self.stats.tokens_generated += 1
-            if self._is_finished(lane, token):
-                self._finish(i, t, reason=self._reason(lane, token))
+        span = self._spans.span
+        with span("engine.tick.prepare"):
+            nb = self.config.max_batch
+            tokens = np.zeros((nb, 1), np.int32)
+            lengths = np.zeros(nb, np.int32)
+            active = np.zeros(nb, bool)
+            for i in active_ids:
+                lane = self._lanes[i]
+                # the incoming token is written at position `length`
+                self.cache.ensure_capacity(i, lane.length + 1)
+                tokens[i, 0] = lane.last_token
+                lengths[i] = lane.length
+                active[i] = True
+            tables = self.cache.device_block_tables()
+        with span("engine.tick.dispatch"):
+            out, self.cache.pages = self._decode(
+                self.params, self.cache.pages, tables,
+                jnp.asarray(lengths), jnp.asarray(tokens), jnp.asarray(active),
+            )
+        with span("engine.tick.wait"):
+            out = np.asarray(jax.block_until_ready(out))
+        with span("engine.tick.commit"):
+            t = self.now()
+            self.stats.decode_steps += 1
+            self.stats.occupancy.append(len(active_ids))
+            self.stats.peak_pages_in_use = max(
+                self.stats.peak_pages_in_use, self.cache.allocator.n_allocated
+            )
+            for i in active_ids:
+                lane = self._lanes[i]
+                token = int(out[i])
+                lane.length += 1
+                lane.last_token = token
+                lane.tokens.append(token)
+                lane.token_times.append(t)
+                self.stats.tokens_generated += 1
+                if self._is_finished(lane, token):
+                    self._finish(i, t, reason=self._reason(lane, token))
 
     # -------------------------------------------------------------------- run
     def _warmup(self, requests: list[GenerationRequest]) -> None:
@@ -331,16 +356,22 @@ class ServeEngine:
         self._warmup(queued)
 
         self._t0 = time.perf_counter()
-        while self._pending or self._waiting or any(self._lanes):
-            self._evict_timeouts()  # freed lanes/pages are reusable this tick
-            self._admit_arrivals()
-            if any(self._lanes):
-                self._decode_tick()
-            elif self._pending:
-                # idle until the next arrival (nothing to batch)
-                wait = self._pending[0].arrival_s - self.now()
-                if wait > 0:
-                    time.sleep(min(wait, 0.01))
+        with self._spans.listening():
+            while self._pending or self._waiting or any(self._lanes):
+                # freed lanes/pages are reusable this tick
+                with self._spans.span("engine.evict"):
+                    self._evict_timeouts()
+                self._admit_arrivals()
+                if any(self._lanes):
+                    self._decode_tick()
+                elif self._pending:
+                    # idle until the next arrival (nothing to batch)
+                    wait = self._pending[0].arrival_s - self.now()
+                    if wait > 0:
+                        time.sleep(min(wait, 0.01))
         self.stats.elapsed_s = self.now()
+        self.stats.host = {k: list(v) for k, v in self._spans.totals.items()}
+        self.stats.compiles = self._spans.compiles
+        self.stats.compile_s = self._spans.compile_s
         self.results.sort(key=lambda r: r.request_id)
         return self.results, self.stats

@@ -100,6 +100,7 @@ class ServeReport:
     elapsed_s: float
     tokens_per_s: float
     goodput_tokens_per_s: float     # tokens of *completed* requests only
+                                    # (a timed-out request is not completed)
     ttft_p50_ms: float
     ttft_p99_ms: float
     per_token_p50_ms: float         # inter-token (decode cadence)
@@ -115,11 +116,12 @@ class ServeReport:
         ttft = [r.ttft_s * 1e3 for r in results]
         gaps = [g * 1e3 for r in results for g in r.inter_token_s()]
         e2e = [r.e2e_s * 1e3 for r in results]
-        completed_tokens = sum(r.n_generated for r in results)
+        completed = [r for r in results if r.finish_reason != "timeout"]
+        completed_tokens = sum(r.n_generated for r in completed)
         elapsed = stats.elapsed_s
         return cls(
             n_requests=len(results),
-            n_completed=len(results),
+            n_completed=len(completed),
             total_tokens=stats.tokens_generated,
             elapsed_s=elapsed,
             tokens_per_s=stats.tokens_per_s,

@@ -1,6 +1,10 @@
 """Continuous-batching engine tests (DESIGN.md §7.2): fast smoke on the
 default tier, batched-vs-sequential token equivalence, mid-flight admission
-under lane pressure, EOS early stop, and page recycling."""
+under lane pressure, EOS early stop, page recycling, and the engine's host
+spans and counters."""
+
+import gc
+import glob
 
 import jax
 import jax.numpy as jnp
@@ -194,3 +198,109 @@ def test_deadline_must_be_positive():
     with pytest.raises(ValueError):
         GenerationRequest(request_id=0, prompt=(1,), max_new_tokens=1,
                           deadline_s=0.0)
+
+
+# ------------------------------------------------------ host spans, counters
+TICK_LEAVES = ("engine.tick.prepare", "engine.tick.dispatch",
+               "engine.tick.wait", "engine.tick.commit")
+ADMIT_LEAVES = ("engine.admit.prefill", "engine.admit.wait")
+LEAVES = ("engine.evict",) + ADMIT_LEAVES + TICK_LEAVES
+
+
+def test_leaf_spans_count_ticks_and_admissions(tiny_model):
+    cfg, model, params = tiny_model
+    engine = ServeEngine(model, params, CFG)
+    _, stats = engine.run(_requests(cfg, 6, seed=11))
+    assert stats.decode_steps > 0 and stats.prefills == 6
+    for name in TICK_LEAVES:
+        assert stats.host[name][0] == stats.decode_steps, name
+    for name in ADMIT_LEAVES:
+        assert stats.host[name][0] == stats.prefills, name
+    # one eviction pass per turn of the loop, at least one per tick
+    assert stats.host["engine.evict"][0] >= stats.decode_steps
+
+
+def test_leaf_spans_sum_within_the_window(tiny_model):
+    cfg, model, params = tiny_model
+    engine = ServeEngine(model, params, CFG)
+    _, stats = engine.run(_requests(cfg, 6, seed=12))
+    assert set(LEAVES) <= set(stats.host)
+    leaves = [stats.host[name][1] for name in LEAVES]
+    assert all(s > 0 for s in leaves)
+    assert sum(leaves) <= stats.elapsed_s
+
+
+def test_compiles_counted_in_the_window(tiny_model):
+    """A warmed run compiles nothing after its clock starts; one whose
+    warm-up skips the prefill buckets compiles them inside the run."""
+    cfg, model, params = tiny_model
+    requests = _requests(cfg, 4, seed=13)
+    _, warmed = ServeEngine(model, params, CFG).run(requests)
+    assert warmed.compiles == 0 and warmed.compile_s == 0.0
+
+    cold = ServeEngine(model, params, CFG)
+    cold._warmup = lambda reqs: ServeEngine._warmup(cold, [])  # decode only
+    _, stats = cold.run(_requests(cfg, 4, seed=13))
+    assert stats.compiles >= 1 and stats.compile_s > 0
+    # the compile happened inside the prefill dispatch
+    assert stats.host["engine.admit.prefill"][1] >= stats.compile_s
+
+
+def test_forced_collection_counts_under_engine_gc(tiny_model):
+    cfg, model, params = tiny_model
+    engine = ServeEngine(model, params, CFG)
+    evict = engine._evict_timeouts
+
+    def evict_and_collect():
+        gc.collect()
+        evict()
+
+    engine._evict_timeouts = evict_and_collect
+    _, stats = engine.run(_requests(cfg, 4, seed=14))
+    calls, seconds = stats.host["engine.gc"]
+    # gc.collect() runs one collection per generation it collects
+    assert calls >= stats.host["engine.evict"][0]
+    assert 0 < seconds <= stats.elapsed_s
+
+
+def _listeners():
+    from jax._src import monitoring
+
+    return list(gc.callbacks), list(monitoring.get_event_time_span_listeners())
+
+
+def test_hooks_removed_after_run_and_after_a_raise(tiny_model):
+    cfg, model, params = tiny_model
+    before = _listeners()
+    ServeEngine(model, params, CFG).run(_requests(cfg, 2, seed=15))
+    assert _listeners() == before
+
+    engine = ServeEngine(model, params, CFG)
+    inside = []
+
+    def broken_tick():
+        inside.append(_listeners())
+        raise RuntimeError("tick failed")
+
+    engine._decode_tick = broken_tick
+    with pytest.raises(RuntimeError, match="tick failed"):
+        engine.run(_requests(cfg, 2, seed=15))
+    hooks, listeners = inside[0]
+    assert len(hooks) == len(before[0]) + 1
+    assert len(listeners) == len(before[1]) + 1
+    assert _listeners() == before
+
+
+def test_spans_land_on_the_profilers_host_plane(tiny_model, tmp_path):
+    from jax.profiler import ProfileData
+
+    cfg, model, params = tiny_model
+    engine = ServeEngine(model, params, CFG)
+    requests = _requests(cfg, 4, seed=16)
+    with jax.profiler.trace(str(tmp_path)):
+        engine.run(requests)
+    [path] = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert set(LEAVES) <= names

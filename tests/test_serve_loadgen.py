@@ -83,6 +83,21 @@ class TestServeReport:
         assert report.e2e_p50_ms == pytest.approx(37.5)  # 25 ms, 50 ms
         assert report.mean_batch_occupancy == pytest.approx(4 / 3)
 
+    def test_timed_out_request_is_not_completed(self):
+        # request 1 was evicted past its deadline after 2 of its tokens
+        results = [
+            _result(0, 0.0, 0.001, [0.005, 0.015, 0.025]),
+            dataclasses.replace(_result(1, 0.01, 0.02, [0.04, 0.06]),
+                                finish_reason="timeout"),
+        ]
+        stats = EngineStats(decode_steps=3, prefills=2, tokens_generated=5,
+                            timeouts=1, elapsed_s=0.1, occupancy=[1, 2, 1])
+        report = ServeReport.from_run(results, stats)
+        assert report.n_requests == 2 and report.n_completed == 1
+        assert report.total_tokens == 5
+        assert report.tokens_per_s == pytest.approx(50.0)
+        assert report.goodput_tokens_per_s == pytest.approx(30.0)
+
     def test_report_round_trips_to_dict(self):
         report = ServeReport.from_run([], EngineStats())
         d = report.to_dict()
