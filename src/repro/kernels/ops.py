@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _fa_pallas
+from repro.kernels.paged_decode import paged_decode_attention as _pd_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rms_pallas
 from repro.kernels.ssd_chunk import ssd_chunk_scan as _ssd_pallas
 
@@ -45,6 +46,29 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
     if impl == "ref" or (impl == "auto" and not _on_tpu()):
         return ref.flash_attention_ref(q, k, v, causal=causal)
     return _fa_pallas(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+                      interpret=_interpret(impl))
+
+
+def paged_decode_impl(page_size: int, kv_width: int, impl: str = "auto") -> str:
+    """What ``impl="auto"`` runs for a pool of ``page_size`` tokens a page
+    and ``kv_width = K*hd``: the kernel on a TPU where a page is whole
+    (16, 128) tiles, else the XLA gather path."""
+    if impl != "auto":
+        return impl
+    fits = page_size % 16 == 0 and kv_width % 128 == 0
+    return "pallas" if _on_tpu() and fits else "ref"
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def paged_decode_attention(q, k_pool, v_pool, layer, block_table, lengths,
+                           active, impl: str = "auto"):
+    """One decode token per lane against the paged pool (signature in
+    kernels/paged_decode.py).  impl: auto|pallas|interpret|ref."""
+    impl = paged_decode_impl(k_pool.shape[2], k_pool.shape[3], impl)
+    if impl == "ref":
+        return ref.paged_decode_attention_ref(q, k_pool, v_pool, layer,
+                                              block_table, lengths, active)
+    return _pd_pallas(q, k_pool, v_pool, layer, block_table, lengths, active,
                       interpret=_interpret(impl))
 
 

@@ -32,6 +32,37 @@ def flash_attention_ref(q, k, v, causal: bool = True):
     return out.astype(q.dtype)
 
 
+def paged_decode_attention_ref(q, k_pool, v_pool, layer, block_table, lengths,
+                               active):
+    """The XLA path of paged decode attention (kernels/paged_decode.py has
+    the signature): gather every lane's whole block table, ``max_blocks *
+    page_size`` slots, then attend with fp32 scores and softmax, bf16
+    probabilities against V, positions ``<= lengths[i]`` of allocated pages.
+    Inactive lanes return zeros."""
+    b, H, hd = q.shape
+    _, n_pages, ps, kd = k_pool.shape
+    K = kd // hd
+    cd = q.dtype
+    safe = jnp.where(block_table >= 0, block_table, 0)
+    idx = (layer * n_pages * ps + safe[:, :, None] * ps
+           + jnp.arange(ps)[None, None, :]).reshape(b, -1)
+    k = k_pool.reshape(-1, K, hd)[idx]  # (b, L, K, hd)
+    v = v_pool.reshape(-1, K, hd)[idx]
+    kpos = jnp.arange(idx.shape[1])
+    valid = (kpos[None, :] <= lengths[:, None]) & jnp.repeat(block_table >= 0, ps, axis=1)
+    k = jnp.repeat(k.astype(cd), H // K, axis=2)
+    v = jnp.repeat(v.astype(cd), H // K, axis=2)
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q[:, None], k, preferred_element_type=jnp.float32
+    ) / np.sqrt(hd)
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    out = jnp.einsum(
+        "bhqk,bkhd->bqhd", probs.astype(cd), v, preferred_element_type=jnp.float32,
+    ).astype(cd)
+    return jnp.where(active[:, None], out.reshape(b, H * hd), 0).astype(cd)
+
+
 def rmsnorm_ref(x, scale, eps: float = 1e-5):
     """x: (..., d); fp32 statistics."""
     x32 = x.astype(jnp.float32)

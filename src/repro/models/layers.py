@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.parallel.sharding import active_mesh, lshard
 
 
@@ -297,34 +298,41 @@ def attention_decode(
 #
 # Serving variant of the cache (DESIGN.md §7): instead of one dense
 # (batch, max_len, ...) buffer per layer, KV lives in fixed-size *pages*
-# shared by all sequences -- {"k","v"}: (n_pages, page_size, K, hd) -- and
-# each sequence owns an ordered *block table* of page ids.  Logical position
-# ``p`` of a sequence maps to physical slot ``table[p // ps] * ps + p % ps``.
-# The allocator/bookkeeping lives in :mod:`repro.serve.kv_cache`; these
-# functions are the pure-JAX compute: scatter new KV into pages, gather a
-# sequence's pages back into a contiguous view, and attend with the same
-# fp32-softmax math as the dense path (so paged and dense decode are
-# token-identical -- the engine equivalence tests rely on it).
+# shared by all sequences -- {"k","v"}: (n_layers, n_pages, page_size, K*hd),
+# every kv head merged into the minor dimension so a page is one unpadded
+# (page_size, K*hd) block -- and each sequence owns an ordered *block table*
+# of page ids.  Logical position ``p`` of a sequence maps to physical slot
+# ``table[p // ps] * ps + p % ps`` of each layer.  The allocator/bookkeeping
+# lives in :mod:`repro.serve.kv_cache`; these functions are the compute:
+# scatter new KV rows into a layer's pages (in place in the pool the layer
+# scan carries), and attend through :func:`repro.kernels.ops.paged_decode_attention`
+# -- the Pallas kernel on a TPU, which reads only each lane's live pages;
+# elsewhere the XLA gather path with the same fp32-softmax math as the dense
+# path (so paged and dense decode are token-identical -- the engine
+# equivalence tests rely on it).
 
 
 def init_paged_kv(n_pages: int, page_size: int, n_kv_heads: int, head_dim: int,
                   dtype=jnp.bfloat16):
-    return {
-        "k": jnp.zeros((n_pages, page_size, n_kv_heads, head_dim), dtype),
-        "v": jnp.zeros((n_pages, page_size, n_kv_heads, head_dim), dtype),
-    }
+    shape = (n_pages, page_size, n_kv_heads * head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _paged_scatter(pages_flat, values, slots):
-    """Write ``values`` (n, K, hd) at flat slots (n,); out-of-range slots
-    (inactive lanes / padding) are dropped, not clamped."""
-    return pages_flat.at[slots].set(values.astype(pages_flat.dtype), mode="drop")
+def _paged_scatter(pool, layer, rows, slots):
+    """Write ``rows`` (n, K*hd) at layer ``layer``'s flat slots (n,) of
+    ``pool`` (n_layers, n_pages, ps, K*hd); out-of-range slots (inactive
+    lanes / padding) are dropped, not clamped."""
+    n_layers, n_pages, ps, kd = pool.shape
+    flat = pool.reshape(n_layers, n_pages * ps, kd)
+    flat = flat.at[layer, slots].set(rows.astype(pool.dtype), mode="drop")
+    return flat.reshape(pool.shape)
 
 
 def attention_decode_paged(
     params,
     x,                 # (b, 1, d) -- one new token per lane
-    pages,             # {"k","v"}: (n_pages, page_size, K, hd)
+    pages,             # {"k","v"}: (n_layers, n_pages, page_size, K*hd)
+    layer,             # scalar int32: this layer's index into the pool
     block_table,       # (b, max_blocks) int32 page ids, -1 = unallocated
     lengths,           # (b,) int32: tokens already cached per lane
     active,            # (b,) bool: lane holds a live sequence
@@ -345,11 +353,11 @@ def attention_decode_paged(
     """
     b = x.shape[0]
     cd = x.dtype
-    n_pages, ps = pages["k"].shape[:2]
+    n_pages, ps = pages["k"].shape[1:3]
     max_blocks = block_table.shape[1]
     q = _split_heads(jnp.einsum("bsd,dh->bsh", x, params["wq"].astype(cd)), n_heads, head_dim)
     k_new = _split_heads(jnp.einsum("bsd,dh->bsh", x, params["wk"].astype(cd)), n_kv_heads, head_dim)
-    v_new = _split_heads(jnp.einsum("bsd,dh->bsh", x, params["wv"].astype(cd)), n_kv_heads, head_dim)
+    v_new = jnp.einsum("bsd,dh->bsh", x, params["wv"].astype(cd))
     pos = lengths[:, None]  # (b, 1)
     if use_rope:
         q = apply_rope(q, pos, rope_theta)
@@ -360,32 +368,21 @@ def attention_decode_paged(
     )[:, 0]
     slots = write_block * ps + lengths % ps
     slots = jnp.where(active & (write_block >= 0), slots, n_pages * ps)  # drop
-    flat_k = _paged_scatter(pages["k"].reshape(n_pages * ps, n_kv_heads, head_dim), k_new[:, 0], slots)
-    flat_v = _paged_scatter(pages["v"].reshape(n_pages * ps, n_kv_heads, head_dim), v_new[:, 0], slots)
-
-    # gather each lane's pages into a contiguous (L = max_blocks*ps) view
-    safe_table = jnp.where(block_table >= 0, block_table, 0)
-    idx = (safe_table[:, :, None] * ps + jnp.arange(ps)[None, None, :]).reshape(b, -1)
-    k = flat_k[idx]  # (b, L, K, hd)
-    v = flat_v[idx]
-    kpos = jnp.arange(max_blocks * ps)
-    valid = (kpos[None, :] <= lengths[:, None]) & jnp.repeat(block_table >= 0, ps, axis=1)
-    k = _repeat_kv(k.astype(cd), n_heads // n_kv_heads)
-    v = _repeat_kv(v.astype(cd), n_heads // n_kv_heads)
-    out = attention_scores(q, k, v, valid[:, None, None, :], compute_dtype=cd)
-    out = out.reshape(b, 1, n_heads * head_dim)
-    proj = jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(cd))
     new_pages = {
-        "k": flat_k.reshape(n_pages, ps, n_kv_heads, head_dim),
-        "v": flat_v.reshape(n_pages, ps, n_kv_heads, head_dim),
+        "k": _paged_scatter(pages["k"], layer, k_new.reshape(b, -1), slots),
+        "v": _paged_scatter(pages["v"], layer, v_new[:, 0], slots),
     }
+    out = ops.paged_decode_attention(
+        q[:, 0], new_pages["k"], new_pages["v"], layer, block_table, lengths, active)
+    proj = jnp.einsum("bsh,hd->bsd", out[:, None], params["wo"].astype(cd))
     return proj, new_pages
 
 
 def attention_prefill_paged(
     params,
     x,                 # (1, S, d) -- padded prompt for one sequence
-    pages,             # {"k","v"}: (n_pages, page_size, K, hd)
+    pages,             # {"k","v"}: (n_layers, n_pages, page_size, K*hd)
+    layer,             # scalar int32: this layer's index into the pool
     block_table,       # (max_blocks,) int32 page ids, -1 = unallocated
     length,            # scalar int32: true prompt length (<= S)
     *,
@@ -404,7 +401,7 @@ def attention_prefill_paged(
     """
     _, s, _ = x.shape
     cd = x.dtype
-    n_pages, ps = pages["k"].shape[:2]
+    n_pages, ps = pages["k"].shape[1:3]
     positions = jnp.arange(s)[None, :]
     q = _split_heads(jnp.einsum("bsd,dh->bsh", x, params["wq"].astype(cd)), n_heads, head_dim)
     k = _split_heads(jnp.einsum("bsd,dh->bsh", x, params["wk"].astype(cd)), n_kv_heads, head_dim)
@@ -417,8 +414,10 @@ def attention_prefill_paged(
     blocks = block_table[(pos // ps) % block_table.shape[0]]
     slots = blocks * ps + pos % ps
     slots = jnp.where((pos < length) & (blocks >= 0), slots, n_pages * ps)
-    flat_k = _paged_scatter(pages["k"].reshape(n_pages * ps, n_kv_heads, head_dim), k[0], slots)
-    flat_v = _paged_scatter(pages["v"].reshape(n_pages * ps, n_kv_heads, head_dim), v[0], slots)
+    new_pages = {
+        "k": _paged_scatter(pages["k"], layer, k[0].reshape(s, -1), slots),
+        "v": _paged_scatter(pages["v"], layer, v[0].reshape(s, -1), slots),
+    }
 
     kr = _repeat_kv(k, n_heads // n_kv_heads)
     vr = _repeat_kv(v, n_heads // n_kv_heads)
@@ -426,10 +425,6 @@ def attention_prefill_paged(
     out = attention_scores(q, kr, vr, mask, compute_dtype=cd)
     out = out.reshape(1, s, n_heads * head_dim)
     proj = jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(cd))
-    new_pages = {
-        "k": flat_k.reshape(n_pages, ps, n_kv_heads, head_dim),
-        "v": flat_v.reshape(n_pages, ps, n_kv_heads, head_dim),
-    }
     return proj, new_pages
 
 

@@ -207,9 +207,9 @@ class DecoderLM:
 
     # ---------------------------------------------------------- paged serve
     def init_paged_cache(self, n_pages: int, page_size: int) -> dict:
-        """Per-layer paged KV pool (DESIGN.md §7): {"k","v"} of shape
-        (n_layers, n_pages, page_size, K, hd).  Page bookkeeping (free list,
-        block tables) lives in :class:`repro.serve.kv_cache.PagedKVCache`."""
+        """Paged KV pool (DESIGN.md §7): {"k","v"} of shape (n_layers,
+        n_pages, page_size, K*hd).  Page bookkeeping (free list, block
+        tables) lives in :class:`repro.serve.kv_cache.PagedKVCache`."""
         cfg = self.cfg
         kv = L.init_paged_kv(n_pages, page_size, cfg.n_kv_heads,
                              cfg.resolved_head_dim, dtype=self.opts.cdt)
@@ -218,13 +218,15 @@ class DecoderLM:
         )
 
     def _paged_layer_stack(self, params, x, attn_fn, pages):
-        """Scan the layer stack threading per-layer pages through
-        ``attn_fn(layer_params, normed_x, layer_pages) -> (h, new_pages)``."""
+        """Scan the layer stack carrying the whole pool, so each layer's
+        writes land in place: ``attn_fn(layer_params, normed_x, pages,
+        layer) -> (h, new_pages)``."""
         cfg = self.cfg
 
-        def body(x, inp):
-            lp, pg = inp
-            h, pg = attn_fn(lp, L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps), pg)
+        def body(carry, inp):
+            x, pg = carry
+            lp, layer = inp
+            h, pg = attn_fn(lp, L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps), pg, layer)
             x = x + h
             normed = L.rmsnorm(lp["ffn_norm"], x, cfg.norm_eps)
             if cfg.is_moe:
@@ -232,9 +234,10 @@ class DecoderLM:
                 h = L.moe_fwd(lp["moe"], normed, top_k=cfg.top_k, capacity_factor=cf)
             else:
                 h = L.mlp_fwd(lp["mlp"], normed)
-            return x + h, pg
+            return (x + h, pg), None
 
-        x, pages = jax.lax.scan(body, x, (params["layers"], pages))
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, pages), _ = jax.lax.scan(body, (x, pages), (params["layers"], layers))
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         return self.logits(params, x), pages
 
@@ -245,8 +248,8 @@ class DecoderLM:
         ``lengths``/``active`` (b,).  Returns (logits (b, 1, V), new pages)."""
         cfg = self.cfg
         x = self.embed(params, tokens)
-        attn = lambda lp, normed, pg: L.attention_decode_paged(
-            lp["attn"], normed, pg, block_tables, lengths, active,
+        attn = lambda lp, normed, pg, layer: L.attention_decode_paged(
+            lp["attn"], normed, pg, layer, block_tables, lengths, active,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
         )
@@ -259,8 +262,8 @@ class DecoderLM:
         new pages); the caller samples from position ``length - 1``."""
         cfg = self.cfg
         x = self.embed(params, tokens)
-        attn = lambda lp, normed, pg: L.attention_prefill_paged(
-            lp["attn"], normed, pg, block_table, length,
+        attn = lambda lp, normed, pg, layer: L.attention_prefill_paged(
+            lp["attn"], normed, pg, layer, block_table, length,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
         )

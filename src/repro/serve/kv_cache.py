@@ -1,13 +1,13 @@
 """Paged/blocked KV cache bookkeeping (DESIGN.md §7.1).
 
 The physical KV store is a pool of fixed-size *pages* shared by every
-sequence -- per layer ``{"k","v"}: (n_pages, page_size, K, hd)`` device
+sequence -- ``{"k","v"}: (n_layers, n_pages, page_size, K*hd)`` device
 arrays owned by :class:`PagedKVCache` -- and each lane (batch slot) owns an
 ordered *block table* of page ids.  Logical token position ``p`` of a lane
 lives at physical slot ``table[p // page_size] * page_size + p % page_size``.
 
 This module is pure host-side bookkeeping (numpy block tables + a free-list
-allocator); the device-side scatter/gather compute is
+allocator); the device-side scatter and attention compute is
 :func:`repro.models.layers.attention_decode_paged` /
 :func:`attention_prefill_paged`, driven by the engine.
 

@@ -11,7 +11,9 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
+from repro.configs import ARCHS
 from repro.kernels.flash_attention import flash_attention as fa_pallas
+from repro.kernels.paged_decode import paged_decode_attention as pd_pallas
 from repro.kernels.rmsnorm import rmsnorm as rms_pallas
 from repro.kernels.ssd_chunk import ssd_chunk_scan as ssd_pallas
 
@@ -164,6 +166,71 @@ class TestSSDChunk:
             ys[:, :, t] = np.einsum("bhpn,bhn->bhp", S, Cn[:, :, t])
         np.testing.assert_allclose(np.asarray(y_pl), ys, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(S_pl), S, rtol=1e-4, atol=1e-4)
+
+
+def _paged_case(H, K, hd, ps, *, b=7, n_layers=2, n_pages=40, max_blocks=10,
+                seed=0):
+    """Pool and lanes for one paged decode call: lengths 0, ps-1, ps, ps+1,
+    the table's maximum, an inactive lane, a live lane with an unallocated
+    (-1) block inside its range, and physical page ids shuffled."""
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pool = (n_layers, n_pages, ps, K * hd)
+    k_pool = rand(ks[0], pool, jnp.bfloat16)
+    v_pool = rand(ks[1], pool, jnp.bfloat16)
+    q = rand(ks[2], (b, H, hd), jnp.bfloat16)
+    lengths = np.array([0, ps - 1, ps, ps + 1, max_blocks * ps - 1, 9, 3 * ps],
+                       np.int32)[:b]
+    active = np.array([True] * 5 + [False, True])[:b]
+    table = np.full((b, max_blocks), -1, np.int32)
+    free = list(rng.permutation(n_pages))
+    for i in range(b):
+        if active[i]:
+            for j in range(-(-(int(lengths[i]) + 1) // ps)):
+                table[i, j] = free.pop()
+    table[6, 1] = -1
+    return (q, k_pool, v_pool, jnp.int32(n_layers - 1), jnp.asarray(table),
+            jnp.asarray(lengths), jnp.asarray(active))
+
+
+#: decoder-served configurations (every DecoderLM family)
+SERVED = sorted(n for n, c in ARCHS.items() if c.family in ("dense", "moe", "vlm"))
+
+
+class TestPagedDecode:
+    @pytest.mark.parametrize("H,K,hd,ps", [
+        (36, 36, 64, 16),    # minicpm-2b: MHA 36 x 64
+        (24, 8, 128, 16),    # phi4-mini: GQA 24/8 x 128
+        (64, 4, 128, 16),    # qwen3-moe: GQA 64/4 x 128
+        (32, 32, 96, 16),    # phi-3-vision: MHA 32 x 96
+        (32, 2, 128, 32),    # glm4-9b widths, 32-token pages (4 a block)
+    ], ids=["minicpm-2b", "phi4-mini", "qwen3-moe", "phi-3-vision", "glm4-page32"])
+    def test_matches_xla_path(self, H, K, hd, ps):
+        """The kernel (interpret mode) against the XLA gather path that
+        ``attention_decode_paged`` runs off the TPU, lane by lane; inactive
+        lanes are zeros on both."""
+        args = _paged_case(H, K, hd, ps)
+        out = pd_pallas(*args, interpret=True)
+        expect = ops.paged_decode_attention(*args, impl="ref")
+        assert out.shape == expect.shape == (args[0].shape[0], H * hd)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(expect, np.float32),
+            rtol=2e-2, atol=2e-2)
+        assert not np.asarray(out[5]).any()
+
+    @pytest.mark.parametrize("arch", SERVED)
+    def test_tpu_dispatch_picks_the_kernel(self, arch, monkeypatch):
+        """On a TPU backend every served config's pool (16-token pages)
+        takes the kernel; pages that are not whole tiles take the XLA path,
+        as does every other backend."""
+        cfg = ARCHS[arch]
+        width = cfg.n_kv_heads * cfg.resolved_head_dim
+        assert ops.paged_decode_impl(16, width) == "ref"
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        assert ops.paged_decode_impl(16, width) == "pallas"
+        assert ops.paged_decode_impl(8, width) == "ref"
+        assert ops.paged_decode_impl(16, width + 64) == "ref"
 
 
 class TestOpsDispatch:

@@ -64,8 +64,8 @@ class _FakeModel:
     """Stands in for DecoderLM: the cache only needs init_paged_cache."""
 
     def init_paged_cache(self, n_pages, page_size):
-        return {"k": np.zeros((2, n_pages, page_size, 1, 4), np.float32),
-                "v": np.zeros((2, n_pages, page_size, 1, 4), np.float32)}
+        return {"k": np.zeros((2, n_pages, page_size, 4), np.float32),
+                "v": np.zeros((2, n_pages, page_size, 4), np.float32)}
 
 
 def _cache(n_pages=8, page_size=4, max_batch=3, max_blocks=4):
