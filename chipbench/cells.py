@@ -1,7 +1,8 @@
 """Cells, configurations, traffic mixes and metric readers, found by name.
 
 ``BENCHMARK.json`` names each cell's configuration (a file under
-``configs/``) and traffic mix (``traffic/<traffic>.json``).  The mix's
+``configs/``) and traffic mix (``traffic/<traffic>.json``); the
+configuration names its plain reference (``reference/``).  The mix's
 ``kind`` names the module that runs it (``serve``).  Every metric is read
 by ``metrics/<name>.py``, whose ``read(run)`` returns a number, or None
 where it finds nothing to read.  Adding a configuration, a mix or a metric
@@ -60,20 +61,55 @@ def load(name: str, bench_path: pathlib.Path = ROOT / "BENCHMARK.json") -> Cell:
 
 
 def arch_config(conf: dict):
-    """The program's ``ArchConfig`` with every size the file states."""
+    """The program's ``ArchConfig``: the registered ``repo_config`` with each
+    ``ARCH_KEYS`` key the file states, and the ``"program"`` object's
+    ``ArchConfig`` fields (sizes with no published key) by their own names.
+    An ``ARCH_KEYS`` key the file leaves out keeps the registered value only
+    where the file names it under ``"registered"``, so that every size run
+    can be read in the file."""
     from repro.configs import get_config
 
-    return dataclasses.replace(get_config(conf["repo_config"]),
-                               **{f: conf[k] for k, f in ARCH_KEYS.items()})
+    unnamed = [k for k in ARCH_KEYS
+               if k not in conf and k not in conf.get("registered", [])]
+    if unnamed:
+        raise ValueError(f"{conf['name']}: {unnamed} neither stated nor "
+                         "named under 'registered'")
+    stated = {f: conf[k] for k, f in ARCH_KEYS.items() if k in conf}
+    # a name that is no ArchConfig field, or a field stated twice, is refused
+    # by ``replace`` (TypeError)
+    return dataclasses.replace(get_config(conf["repo_config"]), **stated,
+                               **conf.get("program", {}))
+
+
+def model(conf: dict):
+    """``(ArchConfig, model)``: the program's model as the file states it,
+    its weights and its computation in the file's ``dtype``."""
+    from repro.models import ModelOptions, build_model
+
+    cfg = arch_config(conf)
+    return cfg, build_model(cfg, ModelOptions(param_dtype=conf["dtype"],
+                                              compute_dtype=conf["dtype"]))
+
+
+def _module(path: pathlib.Path):
+    """The module in ``path``, a file inside the checkout (by its file: a
+    metric's name holds dots)."""
+    name = ".".join(path.relative_to(ROOT).with_suffix("").parts)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(conf: dict, quant=None):
+    """The plain reference that the file names (``"reference"``, a path from
+    the checkout's root): its module's ``make_logits_fn(conf, quant)``."""
+    return _module(ROOT / conf["reference"]).make_logits_fn(conf, quant=quant)
 
 
 def reader(metric: str):
     """``metrics/<metric>.py``'s ``read``."""
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{metric}", HERE / "metrics" / f"{metric}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(HERE / "metrics" / f"{metric}.py").read
 
 
 def read_metrics(entries: list[dict], run) -> dict:

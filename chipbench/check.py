@@ -2,11 +2,12 @@
 
 After the window, a sample of the requests the engine finished (drawn from
 the seed, the longest always in it) is run through the plain float32
-reference over its prompt and served tokens.  The number compared is the
-widest gap by which a served token's reference logit lies below the
-reference's best logit at that position (greedy decoding serves the
-program's best).  The control, the reference computed in fp8 in the
-program's place, is read the same way from the token it puts first.
+reference that the configuration file names (``cells.reference``) over its
+prompt and served tokens.  The number compared is the widest gap by which
+a served token's reference logit lies below the reference's best logit at
+that position (greedy decoding serves the program's best).  The control,
+the reference computed in fp8 in the program's place, is read the same way
+from the token it puts first.
 """
 
 from __future__ import annotations
@@ -47,10 +48,10 @@ def logit_gaps(params, conf: dict, chosen, seq_len: int, n_out: int,
                control: bool = False) -> dict:
     """Widest gap of the served tokens against the reference over the
     ``chosen`` results (and of the control's first choices, if asked)."""
-    from chipbench.reference.decoder import make_logits_fn
+    from chipbench import cells
 
-    ref = make_logits_fn(conf)
-    ctl = make_logits_fn(conf, quant="fp8") if control else None
+    ref = cells.reference(conf)
+    ctl = cells.reference(conf, quant="fp8") if control else None
     out = {"max_logit_gap": 0.0, "tokens": 0}
     if control:
         out["control_max_logit_gap"] = 0.0

@@ -6,9 +6,9 @@ counts are fixed before a chip run.
     JAX_PLATFORMS=cpu python3 chipbench/compile_check.py [cell ...]
 
 For every cell: the engine's decode program and its prefill program at the
-largest bucket the traffic uses, and the reference's program; then the
-page pool that fits beside the weights and the largest program's
-temporaries, against ``USABLE_BYTES``.
+largest bucket the traffic uses, and the program of the reference the
+configuration names; then the page pool that fits beside the weights and
+the largest program's temporaries, against ``USABLE_BYTES``.
 """
 
 from __future__ import annotations
@@ -38,20 +38,17 @@ def check_cell(name: str, chip) -> dict:
     import jax.numpy as jnp
 
     from chipbench import cells
-    from chipbench.reference.decoder import make_logits_fn
     from chipbench.weights import make_params
-    from repro.models import ModelOptions, build_model
     from repro.serve import EngineConfig
     from repro.serve.engine import engine_steps
 
     cell = cells.load(name)
-    cfg = cells.arch_config(cell.conf)
-    model = build_model(cfg, ModelOptions(param_dtype="bfloat16",
-                                          compute_dtype="bfloat16"))
+    cfg, model = cells.model(cell.conf)
     ecfg = EngineConfig(**cell.traffic["engine"])
     n_pages = ecfg.n_pages
     on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)  # noqa: E731
-    params = jax.tree.map(on, jax.eval_shape(lambda: make_params(cfg, 0)))
+    params = jax.tree.map(on, jax.eval_shape(
+        lambda: make_params(cfg, 0, cell.conf["dtype"])))
     pages = jax.tree.map(on, jax.eval_shape(
         lambda: model.init_paged_cache(n_pages, ecfg.page_size)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
@@ -63,7 +60,7 @@ def check_cell(name: str, chip) -> dict:
     bucket = ecfg.prefill_bucket(cell.traffic["prompt_tokens"]["max"])
     out[f"prefill_{bucket}"] = _mem(prefill.lower(
         params, pages, i32(mb), i32(), i32(1, bucket)).compile())
-    ref = make_logits_fn(cell.conf)
+    ref = cells.reference(cell.conf)
     out["reference"] = _mem(ref.lower(
         params, i32(mb * ecfg.page_size),
         i32(cell.traffic["output_tokens"]["max"])).compile())

@@ -47,18 +47,15 @@ def main(argv=None) -> int:
 
     from chipbench import cells, serve
     from chipbench.weights import make_params
-    from repro.models import ModelOptions, build_model
 
     if jax.devices()[0].platform != "tpu":
         print("control: needs a TPU", file=sys.stderr)
         return 2
     cell = cells.load(args.workload)
-    cfg = cells.arch_config(cell.conf)
-    model = build_model(cfg, ModelOptions(param_dtype="bfloat16",
-                                          compute_dtype="bfloat16"))
+    cfg, model = cells.model(cell.conf)
     for seed in args.seeds:
         t0 = time.perf_counter()
-        params = jax.block_until_ready(make_params(cfg, seed))
+        params = jax.block_until_ready(make_params(cfg, seed, cell.conf["dtype"]))
         requests, results, _ = serve_once(cell, model, params, seed, args.seconds)
         served_s = time.perf_counter() - t0
         checks = serve.verify(cell, params, requests, results, seed, control=True)

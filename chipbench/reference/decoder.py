@@ -15,6 +15,8 @@ weights (bfloat16 values, computed in float32) layer by layer inside one
 scan, with attention in blocks of queries, so that it fits one chip beside
 the weights.  ``quant="fp8"`` is the control: every matrix product's
 operands rounded to float8 e4m3 (per-tensor scale), accumulated in float32.
+A reference whose layers differ only in the feed-forward block passes its
+own ``ffn`` and reuses the rest (``moe_decoder.py``).
 """
 
 from __future__ import annotations
@@ -40,9 +42,11 @@ def _quantizer(quant):
     return q
 
 
-def make_logits_fn(conf: dict, quant=None, q_block: int = 512):
+def make_logits_fn(conf: dict, quant=None, q_block: int = 512, ffn=None):
     """``fn(params, tokens (S,), out_pos (P,)) -> logits (P, vocab)`` f32,
-    jitted; ``conf`` is a configuration file's dict."""
+    jitted; ``conf`` is a configuration file's dict.  ``ffn(lp, h, q)``, given
+    a layer's weights, its normed input and the operand rounding (``q``, the
+    identity but in the control), takes the place of the SwiGLU MLP."""
     d, n_h = conf["hidden_size"], conf["num_attention_heads"]
     n_kv = conf["num_key_value_heads"]
     hd = conf.get("head_dim") or d // n_h
@@ -78,6 +82,12 @@ def make_logits_fn(conf: dict, quant=None, q_block: int = 512):
             outs.append(jnp.einsum("krbs,skd->bkrd", q(p), vq).reshape(nb, n_h * hd))
         return jnp.concatenate(outs, 0)
 
+    def swiglu(lp, h, q):
+        mlp = lp["mlp"]
+        return mm(jax.nn.silu(mm(h, mlp["w_gate"])) * mm(h, mlp["w_in"]), mlp["w_out"])
+
+    ffn = ffn or swiglu
+
     def layer(x, lp):
         s_len = x.shape[0]
         pos = jnp.arange(s_len)
@@ -87,9 +97,7 @@ def make_logits_fn(conf: dict, quant=None, q_block: int = 512):
         kh = rope(mm(h, at["wk"]).reshape(s_len, n_kv, hd), pos)
         vh = mm(h, at["wv"]).reshape(s_len, n_kv, hd)
         x = x + mm(attention(qh, kh, vh), at["wo"])
-        h = norm(x, lp["ffn_norm"]["norm_scale"])
-        mlp = lp["mlp"]
-        x = x + mm(jax.nn.silu(mm(h, mlp["w_gate"])) * mm(h, mlp["w_in"]), mlp["w_out"])
+        x = x + ffn(lp, norm(x, lp["ffn_norm"]["norm_scale"]), q)
         return x, None
 
     def fn(params, tokens, out_pos):

@@ -10,9 +10,10 @@ the cell uses, then the window measures for ``--seconds``.  With
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
 window.  The last line of standard output is one JSON object
 (``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, then
-``breakdown`` when traced, and last ``checks``: each number compared with
-its limit, also the last lines of standard error).  Without a TPU, or
-with fewer chips than the cell asks for, it exits 2 and prints no result.
+``breakdown`` when traced, ``host``, where the window's host time went,
+and last ``checks``: each number compared with its limit, also the last
+lines of standard error).  Without a TPU, or with fewer chips than the
+cell asks for, it exits 2 and prints no result.
 JAX's compilation cache is kept in ``chipbench/.cache/jax``.
 """
 
@@ -58,6 +59,7 @@ def main(argv=None) -> int:
 
     out = cells.kind_module(cell).run(cell, args.seed, args.seconds,
                                       bool(args.trace), T_START)
+    print(f"host {json.dumps(out.get('host', {}))}", file=sys.stderr)
     for name, c in out["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['rule']} {c['limit']})",
               file=sys.stderr)

@@ -1,16 +1,17 @@
 """The serve cell: the program's ``ServeEngine`` over one chip.
 
-Set-up makes the weights from the seed, builds the engine and lets
-``ServeEngine.run`` warm up the decode step and the prefill buckets of the
-cell's own prompts.  The window opens when warm-up ends (the engine's clock
-zero) and the requests' arrival times count from there; it closes at the
-end of the first decode tick that ends after ``seconds``.  The backlog's
-requests all carry ``seconds`` as deadline, so ``run`` returns soon after.
+Set-up makes the weights from the seed, in the type the configuration
+states, builds the engine and lets ``ServeEngine.run`` warm up the decode
+step and the prefill buckets of the cell's own prompts.  The window opens
+when warm-up ends (the engine's clock zero) and the requests' arrival times
+count from there; it closes at the end of the first decode tick that ends
+after ``seconds``.  The backlog's requests all carry ``seconds`` as
+deadline, so ``run`` returns soon after.
 
 With a trace, the profiler records that window; the benchmark opens host
 spans around the engine's calls so that idle gaps can be named.  After the
-window, the engine's pages are freed and the plain reference checks a
-sample of the finished requests (``check.py``).
+window, the engine's pages are freed and the configuration's plain
+reference checks a sample of the finished requests (``check.py``).
 """
 
 from __future__ import annotations
@@ -88,6 +89,24 @@ def make_engine(model, params, engine_cfg, seconds: float, trace_dir):
     return Engine(model, params, engine_cfg)
 
 
+#: a gap between successive served tokens longer than this is a stall (a
+#: decode tick takes about 14-20 ms, a prefill up to about 0.1 s)
+STALL_S = 0.25
+
+
+def host_summary(stats, results, close_s: float) -> dict:
+    """Where the window's host time went, for reading a run that reads far
+    off (printed, not a metric): the engine's wait on the device and the
+    stalls, gaps over ``STALL_S`` between successive served tokens."""
+    times = sorted({t for r in results for t in r.token_times_s if t <= close_s})
+    gaps = [b - a for a, b in zip(times, times[1:])]
+    stalls = [g for g in gaps if g > STALL_S]
+    calls, wait_s = stats.host.get("engine.tick.wait", [0, 0.0])
+    return {"tick_wait_s": wait_s, "ticks": calls,
+            "stalls": len(stalls), "stall_s": sum(stalls),
+            "longest_gap_s": max(gaps, default=0.0)}
+
+
 def peak_memory() -> int:
     import jax
 
@@ -99,16 +118,13 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         t_start: float) -> dict:
     """One run of a serve cell; returns the result line's dict."""
     import jax
-    from repro.models import ModelOptions, build_model
     from repro.serve import EngineConfig
 
     from chipbench import traffic as gen
     from chipbench.weights import make_params
 
-    cfg = cells.arch_config(cell.conf)
-    model = build_model(cfg, ModelOptions(param_dtype="bfloat16",
-                                          compute_dtype="bfloat16"))
-    params = jax.block_until_ready(make_params(cfg, seed))
+    cfg, model = cells.model(cell.conf)
+    params = jax.block_until_ready(make_params(cfg, seed, cell.conf["dtype"]))
     requests = gen.requests(cell.traffic, cfg.vocab, seed, seconds)
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     try:
@@ -146,6 +162,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         out["device"].update(busy_s=summary.busy_s, window_s=summary.window_s)
         out["breakdown"] = {"device_ops": [list(x) for x in summary.device_ops],
                             "idle_gaps": [list(x) for x in summary.idle_gaps]}
+    out["host"] = host_summary(stats, results, close_s)
     out["checks"] = checks
     return out
 

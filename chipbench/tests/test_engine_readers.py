@@ -62,3 +62,17 @@ def test_an_engine_without_spans_reads_none(metric):
                                   timeouts=0, elapsed_s=4.0, occupancy=[16] * 10,
                                   peak_pages_in_use=40)
     assert _read(metric, stats) is None
+
+
+def test_host_summary_finds_the_stalls_inside_the_window():
+    from chipbench.serve import host_summary
+
+    stats = EngineStats(decode_steps=10, host=HOST)
+    results = [types.SimpleNamespace(token_times_s=[0.0, 0.02, 1.02, 1.04]),
+               types.SimpleNamespace(token_times_s=[0.02, 1.04, 1.06, 2.5, 4.5])]
+    # tokens at 0, 0.02, 1.02, 1.04, 1.06, 2.5 inside a window closed at 4.0
+    out = host_summary(stats, results, close_s=4.0)
+    assert out["tick_wait_s"] == 3.5 and out["ticks"] == 10
+    assert out["stalls"] == 2
+    assert out["stall_s"] == pytest.approx(1.0 + 1.44)
+    assert out["longest_gap_s"] == pytest.approx(1.44)

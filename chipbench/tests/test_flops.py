@@ -35,6 +35,22 @@ def test_decode_token_flops_by_hand():
     assert flops.decode_token_flops(c, n_keys) == want
 
 
+def test_moe_decode_token_flops_by_hand():
+    from chipbench.tests.helpers import MOE_CELL
+
+    c = cells.arch_config(reduced_cell(MOE_CELL).conf)
+    assert (c.n_experts, c.top_k, c.expert_ff) == (4, 2, 64)
+    d, hd = c.d_model, c.resolved_head_dim
+    mats = [(d, c.n_heads * hd), (d, c.n_kv_heads * hd), (d, c.n_kv_heads * hd),
+            (c.n_heads * hd, d), (d, c.n_experts)]             # ... and the router
+    for _ in range(c.top_k):                                   # each expert routed to
+        mats += [(d, c.expert_ff), (d, c.expert_ff), (c.expert_ff, d)]
+    layer = sum(2 * k * n for k, n in mats)
+    attn = c.n_layers * c.n_heads * 2 * (2 * hd * 37)
+    want = c.n_layers * layer + 2 * d * c.vocab + attn
+    assert flops.decode_token_flops(c, 37) == want
+
+
 def test_peaks_are_keyed_by_device_kind():
     import pytest
 

@@ -1,6 +1,7 @@
 """Rehearsal of each serve cell end to end on the CPU at ``reduced()`` sizes
 (the chip check skipped), and the same run with the timed path broken
-underneath, which must come out not correct."""
+underneath, which must come out not correct, also in the test-only cell
+with sparse experts."""
 
 import time
 
@@ -10,7 +11,7 @@ import pytest
 
 import repro.serve.engine as engine_mod
 from chipbench import serve
-from chipbench.tests.helpers import SERVE_CELLS, reduced_cell
+from chipbench.tests.helpers import MOE_CELL, SERVE_CELLS, reduced_cell
 
 SECONDS = 3.0
 SEED = 2**31 + 99
@@ -62,7 +63,7 @@ def _state_unchanged(model):
 
 @pytest.mark.parametrize("fault", [_token_altered, _state_unchanged],
                          ids=["token-altered", "state-unchanged"])
-@pytest.mark.parametrize("name", SERVE_CELLS)
+@pytest.mark.parametrize("name", SERVE_CELLS + [MOE_CELL])
 def test_broken_path_is_not_correct(name, fault, monkeypatch):
     monkeypatch.setattr(engine_mod, "engine_steps", fault)
     out = _run(name)

@@ -1,12 +1,17 @@
 """The served weights, made by the benchmark from the seed.
 
-One jitted call builds every leaf on the device, in the type the model is
-served in, in the parameter layout the program's ``DecoderLM`` takes.  The
-plain reference reads these same arrays; nothing of them comes from the
-program.
+The layout, every leaf's path, shape and type, is the program's own
+parameter tree: ``jax.eval_shape`` of its ``init``, so any decoder the
+program builds (dense, experts, a patch projection) gets every leaf it
+takes.  The values come only from the seed, by rules on the leaf's path and
+shape, in one jitted call on the device.  The plain reference reads these
+same arrays; no value of them comes from the program.
 """
 
 from __future__ import annotations
+
+import math
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -20,17 +25,30 @@ def seed_key(seed: int) -> jax.Array:
 #: row blocks of a 2-D leaf generated one at a time
 ROW_BLOCKS = 16
 
+#: the dense decoder's leaves and their fixed places in ``split(key, 12)``,
+#: so that a dense model's weights do not depend on the rest of the tree;
+#: any other leaf draws from the key folded with its path
+DENSE_KEYS = {
+    "embed/tokens": 0,
+    "layers/attn/wq": 1, "layers/attn/wk": 2, "layers/attn/wv": 3,
+    "layers/attn/wo": 4,
+    "layers/attn_norm/norm_scale": 5, "layers/ffn_norm/norm_scale": 6,
+    "layers/mlp/w_gate": 7, "layers/mlp/w_in": 8, "layers/mlp/w_out": 9,
+    "final_norm/norm_scale": 10,
+    "lm_head": 11,
+}
+
 
 def _normal(key, shape, std, dtype):
-    """Normal values of ``shape`` generated block by block along the leading
-    axis (``lax.map``: one layer, or one of ``ROW_BLOCKS`` row blocks of a
-    matrix), so the float32 temporaries stay one block."""
+    """Normal values of ``shape`` generated block by block (``lax.map``: one
+    of ``ROW_BLOCKS`` row blocks of a matrix, or one matrix of a stack), so
+    the float32 temporaries stay one block."""
     if len(shape) == 2:
         if shape[0] % ROW_BLOCKS:
             raise ValueError(f"{shape[0]} rows are not a multiple of {ROW_BLOCKS}")
         lead, block = ROW_BLOCKS, (shape[0] // ROW_BLOCKS, shape[1])
     else:
-        lead, block = shape[0], tuple(shape[1:])
+        lead, block = math.prod(shape[:-2]), tuple(shape[-2:])
 
     def one(k):
         return (jax.random.normal(k, block, jnp.float32) * std).astype(dtype)
@@ -38,44 +56,39 @@ def _normal(key, shape, std, dtype):
     return jax.lax.map(one, jax.random.split(key, lead)).reshape(shape)
 
 
+def _leaf(key, name: str, leaf):
+    """One leaf's values: norm scales 1 + 0.1 normal, the embedding std
+    1/sqrt(d_model), any other matrix or stack of matrices std
+    1/sqrt(fan_in), its second-to-last axis."""
+    shape, dtype = leaf.shape, leaf.dtype
+    if name.endswith("norm_scale"):
+        x = jax.random.normal(key, shape, jnp.float32)
+        return (1.0 + 0.1 * x).astype(dtype)
+    if name == "embed/tokens":
+        return _normal(key, shape, shape[-1] ** -0.5, dtype)
+    if len(shape) >= 2:
+        return _normal(key, shape, shape[-2] ** -0.5, dtype)
+    raise ValueError(f"no rule makes the weights of leaf {name!r} {shape} {dtype}")
+
+
 def make_params(cfg, seed: int, dtype=jnp.bfloat16) -> dict:
-    """Random weights of ``cfg`` (a ``repro`` ``ArchConfig``): matrices
-    normal with std 1/sqrt(fan_in), embeddings std 1/sqrt(d_model), norm
-    scales 1 + 0.1 normal."""
-    d, hd, h, kv = cfg.d_model, cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    f, n, vp = cfg.d_ff, cfg.n_layers, cfg.padded_vocab
+    """Random weights of ``cfg`` (a ``repro`` ``ArchConfig``) in the program's
+    parameter tree, its matrices in ``dtype`` (a leaf the program keeps in
+    another type, as the experts' router, keeps that type)."""
+    from repro.models import ModelOptions, build_model
+
+    model = build_model(cfg, ModelOptions(param_dtype=jnp.dtype(dtype).name))
+    layout = jax.eval_shape(lambda: model.init(jax.random.key(0)))
 
     def build(key):
-        keys = iter(jax.random.split(key, 12))
+        dense = jax.random.split(key, len(DENSE_KEYS))
 
-        def normal(shape, fan_in):
-            return _normal(next(keys), shape, fan_in ** -0.5, dtype)
+        def one(path, leaf):
+            name = "/".join(str(p.key) for p in path)
+            k = (dense[DENSE_KEYS[name]] if name in DENSE_KEYS
+                 else jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF))
+            return _leaf(k, name, leaf)
 
-        def scale(shape):
-            x = jax.random.normal(next(keys), shape, jnp.float32)
-            return {"norm_scale": (1.0 + 0.1 * x).astype(dtype)}
-
-        params = {
-            "embed": {"tokens": normal((vp, d), d)},
-            "layers": {
-                "attn": {
-                    "wq": normal((n, d, h * hd), d),
-                    "wk": normal((n, d, kv * hd), d),
-                    "wv": normal((n, d, kv * hd), d),
-                    "wo": normal((n, h * hd, d), h * hd),
-                },
-                "attn_norm": scale((n, d)),
-                "ffn_norm": scale((n, d)),
-                "mlp": {
-                    "w_gate": normal((n, d, f), d),
-                    "w_in": normal((n, d, f), d),
-                    "w_out": normal((n, f, d), f),
-                },
-            },
-            "final_norm": scale((d,)),
-        }
-        if not cfg.tie_embeddings:
-            params["lm_head"] = normal((d, vp), d)
-        return params
+        return jax.tree_util.tree_map_with_path(one, layout)
 
     return jax.jit(build)(seed_key(seed))
